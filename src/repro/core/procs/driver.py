@@ -45,6 +45,7 @@ from __future__ import annotations
 import os
 import pickle
 import signal
+import sys
 import threading
 import time
 import traceback
@@ -76,6 +77,13 @@ PROC_MODES = ("sync", "dast", "ddast", "sharded")
 
 __all__ = ["ProcessDispatch", "ProcessRuntime", "TaskFailed",
            "WorkerLost", "RingCorruption", "FaultPlan", "PROC_MODES"]
+
+
+def _tpu_backend_initialized() -> bool:
+    """Whether this process has brought up JAX's TPU backend, and so
+    holds the chip. Looks without importing JAX."""
+    xb = sys.modules.get("jax._src.xla_bridge")
+    return xb is not None and "tpu" in getattr(xb, "_backends", {})
 
 
 # ---------------------------------------------------------------------------
@@ -758,6 +766,14 @@ class ProcessRuntime:
     def start(self) -> None:
         if self._started:
             return
+        if _tpu_backend_initialized():
+            # one process per chip: a forked worker inherits a TPU client
+            # it cannot use, and one that touches JAX fails or hangs
+            raise RuntimeError(
+                "ProcessRuntime cannot fork workers: this process has "
+                "initialized JAX's TPU backend and holds the chip. Start "
+                "the process runtime before touching JAX, or use the "
+                "threads backend")
         import multiprocessing as mp
         methods = mp.get_all_start_methods()
         self._ctx = mp.get_context(

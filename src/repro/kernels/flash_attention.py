@@ -10,6 +10,10 @@ sliding-window locality (Gemma-2), attn-logit softcapping. Causal/window
 block skipping is done with `pl.when` on block indices, so fully-masked
 KV blocks cost nothing on TPU.
 
+The backward pass has no kernel of its own: the VJP recomputes the
+reference attention and differentiates that, so a train step keeps the
+kernel in its forward pass (Pallas calls have no autodiff rule).
+
 Oracle: kernels/ref.py::attention_ref (tests sweep shapes/dtypes in
 interpret mode).
 """
@@ -23,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .ref import attention_ref
 
 NEG_INF = -1e30
 
@@ -93,6 +99,33 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     blk_q: int = 128, blk_k: int = 128,
                     interpret: bool = False) -> jax.Array:
     """q [B,S,nq,hd]; k/v [B,T,nkv,hd] -> [B,S,nq,hd]."""
+    return _flash(q, k, v, causal, window, softcap, blk_q, blk_k, interpret)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, window, softcap, blk_q, blk_k, interpret):
+    return _flash_forward(q, k, v, causal, window, softcap, blk_q, blk_k,
+                          interpret)
+
+
+def _flash_fwd(q, k, v, causal, window, softcap, blk_q, blk_k, interpret):
+    out = _flash_forward(q, k, v, causal, window, softcap, blk_q, blk_k,
+                         interpret)
+    return out, (q, k, v)
+
+
+def _flash_bwd(causal, window, softcap, blk_q, blk_k, interpret, res, g):
+    del blk_q, blk_k, interpret
+    _, vjp = jax.vjp(functools.partial(attention_ref, causal=causal,
+                                       window=window, softcap=softcap), *res)
+    return vjp(g)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _flash_forward(q, k, v, causal, window, softcap, blk_q, blk_k,
+                   interpret):
     b, s, nq, hd = q.shape
     t, nkv = k.shape[1], k.shape[2]
     g = nq // nkv

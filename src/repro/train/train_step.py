@@ -110,8 +110,13 @@ def make_prefill_step(model: ModelAPI) -> Callable:
 
 
 def make_serve_step(model: ModelAPI) -> Callable:
+    vocab = model.cfg.vocab_size
+
     def serve_step(params: Params, cache: Params, tokens: jax.Array,
                    pos: jax.Array):
         logits, cache = model.decode_step(params, cache, tokens, pos)
+        # the unembedding is padded past the vocabulary (layers.padded_vocab);
+        # the padding columns are no tokens, so argmax never sees them
+        logits = logits[..., :vocab]
         return jnp.argmax(logits, axis=-1).astype(jnp.int32), logits, cache
     return serve_step

@@ -185,7 +185,7 @@ def test_scope_slo_attainment_met():
         sc = rt.open_scope("tenantA", deadline=30.0)
         for i in range(12):
             sc.task(_spin, label=f"t{i}")
-        rt.taskwait()
+        sc.taskwait()           # scope tasks are not the root's children
         live = rt.metrics()["scopes"]["tenantA"]["slo"]
         assert live["met"] == 12 and live["missed"] == 0
         assert live["attainment"] == 1.0

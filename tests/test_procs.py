@@ -301,6 +301,24 @@ def test_backend_dispatch_and_validation():
         ProcessRuntime(mode="warp")
 
 
+def test_refuses_to_fork_once_tpu_backend_is_up(monkeypatch):
+    """One process per chip: once this process has brought up JAX's TPU
+    backend, start() raises before forking anything."""
+    from jax._src import xla_bridge
+    monkeypatch.setitem(xla_bridge._backends, "tpu", object())
+    rt = ProcessRuntime(num_workers=2, mode="sharded")
+    try:
+        with pytest.raises(RuntimeError, match="TPU backend"):
+            rt.start()
+    finally:
+        if rt._started:                  # forked after all: clean up
+            rt.shutdown()
+    assert not rt._procs and not rt.shm_names()
+    monkeypatch.undo()                   # no TPU: the same runtime forks
+    rt.start()
+    _assert_no_leaks(rt)
+
+
 # ------------------------------------------------------------ rings
 def test_ring_wraparound():
     ring = ShmRing(capacity=256)

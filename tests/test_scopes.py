@@ -528,6 +528,9 @@ def test_fair_admission_default_context_bypasses_rings():
 class _StubModel:
     """Just enough ModelAPI for the request layer: constant logits."""
 
+    class cfg:                                 # noqa: N801 (ModelAPI.cfg)
+        vocab_size = 16
+
     def init_cache(self, batch, max_len):
         return {}
 
