@@ -6,7 +6,9 @@ Trace Event Format that ``ui.perfetto.dev`` and ``chrome://tracing``
 load directly:
 
   * one lane per worker slot (pid 0) with a complete-event ("X") slice
-    per task body, colored by scope so tenants are visually separable;
+    per task body, colored by scope so tenants are visually separable,
+    and one per recorded span (``manager`` sessions, the serving
+    engine's step phases) on the slot that ran it;
   * instant events ("i") on the owning lane for the pre-execution
     lifecycle (``created`` / ``deps_resolved`` / ``ready``), steals
     (thief lane, victim in args) and admission deferrals;
@@ -36,9 +38,9 @@ from typing import Dict, List, Optional, Sequence
 
 from repro.core.trace import (EV_ADMIT_DEFER, EV_COMBINE, EV_CREATED,
                               EV_DELEGATE, EV_DEPS, EV_END, EV_MSG_DRAIN,
-                              EV_MSG_ENQ, EV_QUIESCE, EV_READY, EV_START,
-                              EV_STEAL, TraceEvent, detect_all,
-                              load_trace)
+                              EV_MSG_ENQ, EV_QUIESCE, EV_READY, EV_SPAN,
+                              EV_START, EV_STEAL, TraceEvent, detect_all,
+                              load_trace, span_end)
 
 # chrome://tracing reserved color names, cycled per scope (None = the
 # driver's own root context gets the first entry)
@@ -89,6 +91,13 @@ def to_chrome_trace(events: Sequence[TraceEvent],
                         "ts": s.t * k, "dur": max((e.t - s.t) * k, 0.0),
                         "cat": "task", "cname": _scope_color(e.scope),
                         "args": {"wd_id": e.wd_id, "scope": e.scope}})
+        elif e.ev == EV_SPAN:
+            args = {} if e.data[1] is None else {"data": e.data[1]}
+            out.append({"name": e.label, "ph": "X", "pid": _WORKERS_PID,
+                        "tid": e.slot if e.slot >= 0 else 0,
+                        "ts": e.t * k,
+                        "dur": max((span_end(e) - e.t) * k, 0.0),
+                        "cat": "span", "args": args})
         elif e.ev in (EV_CREATED, EV_DEPS, EV_READY, EV_STEAL,
                       EV_ADMIT_DEFER):
             args = {"wd_id": e.wd_id, "scope": e.scope}

@@ -43,7 +43,8 @@ from ..depgraph import DependenceGraph
 from ..messages import DoneTaskMessage, SubmitTaskMessage
 from ..queues import InstrumentedLock, WorkerQueues
 from ..shards import ShardRouter, ShardedDependenceGraph
-from ..trace import EV_DEPS, EV_MSG_DRAIN, EV_MSG_ENQ, NULL_TRACER
+from ..trace import (COUNT_EMPTY_POLL, EV_DEPS, EV_MSG_DRAIN, EV_MSG_ENQ,
+                     NULL_TRACER, SPAN_MANAGER)
 from ..wd import WorkDescriptor
 from .charge import CostCharger
 from .placement import PlacementPolicy, RoundRobinPlacement
@@ -103,8 +104,20 @@ class DependencePolicy:
 
     def callback(self, worker_id: int) -> int:
         """Dispatcher-facing name (historically DDASTManager.callback) —
-        delegates so subclasses only ever override ``idle_callback``."""
-        return self.idle_callback(worker_id)
+        delegates so subclasses only ever override ``idle_callback``.
+        Every idle-thread manager session enters here, so this is where
+        a traced run records it: a ``manager`` span on the calling slot
+        when the session processed messages, else one ``empty_poll``."""
+        tr = self.tracer
+        if not tr.enabled:
+            return self.idle_callback(worker_id)
+        t0 = tr.clock()
+        n = self.idle_callback(worker_id)
+        if n:
+            tr.span(SPAN_MANAGER, worker_id, t0, n)
+        else:
+            tr.count(COUNT_EMPTY_POLL, worker_id)
+        return n
 
     def drain_all(self) -> int:
         return 0
@@ -321,8 +334,8 @@ class DdastPolicy(_GlobalGraphMixin, _ManagedPolicy):
         the previous pass stopped: MIN_READY stops most passes after one
         queue, so a fixed (or naively rotating) start lets the producer
         of a favored queue own readiness production — the continuation
-        cursor makes first service a true round-robin over queues."""
-        del worker_id
+        cursor makes first service a true round-robin over queues.
+        Drains are stamped on ``worker_id``, the managing slot."""
         p = self.params
         quantum = p.drain_quantum
         consumed: Dict[object, int] = {}
@@ -358,7 +371,7 @@ class DdastPolicy(_GlobalGraphMixin, _ManagedPolicy):
                         self.charge.message()
                         if self.tracer.enabled:
                             self.tracer.task_event(
-                                EV_MSG_DRAIN, msg.wd, -1,
+                                EV_MSG_DRAIN, msg.wd, worker_id,
                                 data=("submit", wq.worker_id, 1))
                         self._apply_submit(msg.wd)
                         cnt += 1
@@ -381,7 +394,7 @@ class DdastPolicy(_GlobalGraphMixin, _ManagedPolicy):
                 self.scope_drained[sc] = self.scope_drained.get(sc, 0) + 1
                 self.charge.message()
                 if self.tracer.enabled:
-                    self.tracer.task_event(EV_MSG_DRAIN, msg.wd, -1,
+                    self.tracer.task_event(EV_MSG_DRAIN, msg.wd, worker_id,
                                            data=("done", wq.worker_id, 1))
                 self._apply_done(msg.wd)
                 cnt += 1

@@ -150,8 +150,10 @@ def _deps_key(wd: WorkDescriptor) -> _DepsKey:
 
 def _task_cost(wd: WorkDescriptor) -> Optional[float]:
     """The task's measured cost: real body time (threaded driver's
-    ``exec_dur``, seconds) or virtual duration (simulator, µs) — only
-    relative magnitude matters and the two never mix within a run.
+    ``exec_dur``, seconds: host time, so for a JAX body the dispatch of
+    its jitted call, not its device work) or virtual duration
+    (simulator, µs) — only relative magnitude matters and the two never
+    mix within a run.
     ``None`` when no measurement exists (the bottom-level fallback is a
     unit cost, i.e. chain length)."""
     c = getattr(wd, "exec_dur", None)

@@ -177,6 +177,9 @@ class MetricsHub:
     """The driver-side instrument bundle: task start/finish counters,
     busy flags, summed exec time and a latency histogram — all per
     slot, all single-writer, aggregated only in :meth:`snapshot`.
+    Exec time is the driver's body time (``wd.exec_dur``): host time,
+    so for a JAX body the dispatch of its jitted call, not its device
+    work.
 
     ``charge`` is the simulator's :class:`SimCharger` (or ``None`` on
     real drivers): each instrument write prices one ``metric_event`` of

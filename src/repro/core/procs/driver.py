@@ -647,10 +647,9 @@ class ProcessRuntime:
         # process i (trace attribution only — workers hold no policy
         # state)
         self._trace_t0 = time.perf_counter()
-        self.tracer = TraceRecorder(
-            2 + num_workers,
-            clock=lambda: time.perf_counter() - self._trace_t0,
-            time_unit="s") if trace else NULL_TRACER
+        self.tracer = TraceRecorder(2 + num_workers,
+                                    origin=self._trace_t0) \
+            if trace else NULL_TRACER
         self._dispatch = ProcessDispatch(self)
         self._dispatch.record_payloads = replay
         self.placement = self._dispatch
@@ -779,6 +778,8 @@ class ProcessRuntime:
         self._ctx = mp.get_context(
             "fork" if "fork" in methods else methods[0])
         self._trace_t0 = time.perf_counter()
+        if self.trace_enabled:
+            self.tracer.origin = self._trace_t0
         self._main_thread = threading.current_thread()
         # ONE lock, created before the workers exist, guards every
         # replay-plane mutation (latches, ready ring, remaining); a
@@ -958,7 +959,7 @@ class ProcessRuntime:
         self.stats.trace_lost = self.trace_lost_n
         self.stats.zombie_workers = self.zombies
         self.stats.leaked_shm = list(self.leaked_shm)
-        if self.tracer.enabled:
+        if self.trace_enabled:
             self.stats.events = self.tracer.events()
             self.stats.trace_dropped = self.tracer.dropped
         rep = st.get("replay")

@@ -45,10 +45,12 @@ slot, and a weighted-fair share of ready-task admission
 Client threads each own one submit slot, preserving the §3.1
 single-producer queue discipline.
 
-The runtime is instrumented with exactly the quantities the paper plots:
+The runtime is instrumented with the quantities the paper plots:
 graph-lock wait time (per-shard waits summed under the sharded policy),
-in-graph/ready task counts over time (Figs 12-14), message counts, and
-task throughput.
+message counts, and task throughput; ``trace=True`` adds the per-slot
+event timeline of ``core.trace`` (task lifecycle, manager messages and
+sessions), whose recording can be switched on for one stretch of a run
+(``rt.tracer.enabled``).
 """
 from __future__ import annotations
 
@@ -96,7 +98,6 @@ class RuntimeStats:
     ddast_callback_entries: int = 0
     max_in_graph: int = 0
     total_edges: int = 0
-    trace: List[Tuple[float, int, int]] = field(default_factory=list)  # (t, in_graph, ready)
     # Per-task event timeline (core.trace; empty unless trace=True):
     # merged, time-sorted TraceEvents from every slot's ring buffer,
     # plus the count evicted by ring overflow.
@@ -224,9 +225,8 @@ class TaskRuntime:
         # the event tracer must exist before the policy stack: the
         # policy ctor wires it into the placement, the router, etc.
         self._trace_t0 = time.perf_counter()
-        self.tracer = TraceRecorder(
-            num_slots, clock=lambda: time.perf_counter() - self._trace_t0,
-            time_unit="s") if trace else NULL_TRACER
+        self.tracer = TraceRecorder(num_slots, origin=self._trace_t0) \
+            if trace else NULL_TRACER
         # shard-id affinity keying only makes sense over a shard
         # partition; other modes keep exact-region keying
         self.placement = make_placement(
@@ -283,7 +283,6 @@ class TaskRuntime:
         self._threads: List[threading.Thread] = []
         self._manager_thread: Optional[threading.Thread] = None
         self.stats = RuntimeStats()
-        self._trace_t0 = time.perf_counter()
         # multi-tenant bookkeeping (inert when num_clients == 0)
         self._scopes: List[JobScope] = []
         self._scope_seq = itertools.count(1)
@@ -334,6 +333,8 @@ class TaskRuntime:
 
     def start(self) -> None:
         self._trace_t0 = time.perf_counter()
+        if self.trace_enabled:
+            self.tracer.origin = self._trace_t0
         self._main_thread = threading.current_thread()
         _tls.current = self._root
         _tls.worker_id = self.num_workers  # main thread owns the last slot
@@ -386,7 +387,7 @@ class TaskRuntime:
         pst = self.placement.stats()
         self.stats.worker_steals = [d.stolen for d in self.placement.deques]
         self.stats.load_cap_skips = int(pst.get("load_cap_skips", 0))
-        if self.tracer.enabled:
+        if self.trace_enabled:
             self.stats.events = self.tracer.events()
             self.stats.trace_dropped = self.tracer.dropped
         rep = st.get("replay")
@@ -424,12 +425,6 @@ class TaskRuntime:
 
     def _pending_msgs(self) -> int:
         return self.policy.pending()
-
-    def _sample_trace(self) -> None:
-        if self.trace_enabled:
-            self.stats.trace.append((time.perf_counter() - self._trace_t0,
-                                     self.in_graph_count(),
-                                     self.ready_count()))
 
     # ------------------------------------------------------------------
     # live metrics plane (core.metrics)
@@ -540,7 +535,6 @@ class TaskRuntime:
         if self.tracer.enabled:
             self.tracer.task_event(EV_CREATED, wd, wid)
         self.policy.submit(wd, wid)
-        self._sample_trace()
         return wd
 
     def taskwait(self) -> None:
@@ -782,7 +776,10 @@ class TaskRuntime:
                                  list(wd.attempts)))
                     break
         finally:
-            # measured body time feeds the replay scheduler's cost EMA
+            # host time in the body: for a JAX body, the dispatch of its
+            # jitted call (the device work runs on after it); feeds the
+            # replay scheduler's cost EMA, scope budgets, the metrics
+            # plane's exec histogram
             wd.exec_dur = time.perf_counter() - t0
             wd.mark_finished()
             _tls.current, _tls.worker_id = prev_task, prev_wid
@@ -797,7 +794,6 @@ class TaskRuntime:
             self.stats.tasks_executed += 1
         self.placement.note_executed(wd, worker_id)
         self.policy.complete(wd, worker_id)
-        self._sample_trace()
 
     def _charge_scope(self, wd: WorkDescriptor, slot: int = -1) -> None:
         """Charge a finished body against its scope's execution-time
@@ -869,8 +865,7 @@ class TaskRuntime:
             if wd is not None:
                 self._execute(wd, worker_id)
                 continue
-            if self.dispatcher.notify_idle(worker_id):
-                self._sample_trace()
+            self.dispatcher.notify_idle(worker_id)
             time.sleep(0)                   # yield (busy-wait analogue)
 
     def _manager_loop(self) -> None:

@@ -81,8 +81,10 @@ class JobScope:
         self.max_inflight = max_inflight
         # expiry bounds: wall-clock seconds from open, and summed
         # body-execution seconds (charged by the runtime per finished
-        # task). Once either runs out, FairAdmission drains this
-        # scope's queued tasks unrun and taskwait raises ScopeExpired.
+        # task from ``wd.exec_dur``: host time, so a JAX body is charged
+        # its dispatch, not its device work). Once either runs out,
+        # FairAdmission drains this scope's queued tasks unrun and
+        # taskwait raises ScopeExpired.
         self.deadline = deadline
         self.budget = budget
         self._budget_used = 0.0

@@ -56,8 +56,11 @@ class WorkDescriptor:
     parent: Optional["WorkDescriptor"] = None
     duration: Optional[float] = None  # virtual duration for the simulator
     # Measured body execution time (seconds), stamped by the threaded
-    # driver — feeds the replay scheduler's per-task cost EMA (the
-    # simulator uses `duration` for the same purpose).
+    # driver: host time in the body, which for a JAX body is the
+    # dispatch of its jitted call, not its device work. Feeds the
+    # replay scheduler's per-task cost EMA (the simulator uses
+    # `duration` for the same purpose), scope budgets and the metrics
+    # plane's exec histogram.
     exec_dur: Optional[float] = None
     # Multi-tenant job-scope id (core.scopes): None outside any scope;
     # inherited from the parent at creation so every descendant of a

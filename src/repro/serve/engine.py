@@ -22,6 +22,14 @@ requests reach the admission buffer first, and per-client
 ``max_inflight`` backpressure bounds a flooding client's presence in
 the shared pool. Request ids are per-engine (stamped at submit), so two
 engines number their requests independently.
+
+With ``trace=True`` the engine owns a one-slot
+:class:`~repro.core.trace.TraceRecorder` (``engine.tracer``) and each
+step records four spans on it: ``admit`` (draining and admission, the
+slot-cache resets included; payload: requests admitted), ``dispatch``
+(the token and position uploads and the step's launch), ``readback``
+(the host waiting for the step's tokens) and ``track`` (the per-slot
+loop). Switch ``engine.tracer.enabled`` to record one stretch of a run.
 """
 from __future__ import annotations
 
@@ -39,6 +47,8 @@ from ..core.ddast import DDASTParams
 from ..core.metrics import LogHistogram, prometheus_text
 from ..core.queues import WorkerQueues
 from ..core.sched import DagNode, bottom_levels, build_arrays
+from ..core.trace import (NULL_TRACER, SPAN_ADMIT, SPAN_DISPATCH,
+                          SPAN_READBACK, SPAN_TRACK, TraceRecorder)
 from ..models.registry import ModelAPI
 
 
@@ -78,9 +88,10 @@ class ServeEngine:
                  client_max_inflight: Optional[Sequence[Optional[int]]]
                  = None,
                  client_deadlines: Optional[Sequence[Optional[float]]]
-                 = None):
+                 = None, trace: bool = False):
         self.model = model
         self.params = params
+        self.tracer = TraceRecorder(1) if trace else NULL_TRACER
         self.B = batch_slots
         self.max_len = max_len
         self.eos_id = eos_id
@@ -280,14 +291,25 @@ class ServeEngine:
     def step(self) -> int:
         """One engine iteration: drain client queues (manager), then one
         batched decode step. Returns number of active slots advanced."""
+        tr = self.tracer
+        on = tr.enabled
+        if on:
+            t = tr.clock()
+            admitted = self.stats["admitted"]
         self._admit_requests()
+        if on:
+            t = tr.span(SPAN_ADMIT, 0, t, self.stats["admitted"] - admitted)
         active = [i for i, s in enumerate(self.slots) if not s.free]
         if not active:
             return 0
         next_tok, _, self.cache = self._step_fn(
             self.params, self.cache, jnp.asarray(self._tokens),
             jnp.asarray(self._pos))
+        if on:
+            t = tr.span(SPAN_DISPATCH, 0, t)
         next_tok = np.asarray(next_tok)
+        if on:
+            t = tr.span(SPAN_READBACK, 0, t)
         self.steps += 1
         for i in active:
             slot = self.slots[i]
@@ -311,6 +333,8 @@ class ServeEngine:
                     slot.req = None
                     continue
             self._pos[i] = slot.pos
+        if on:
+            tr.span(SPAN_TRACK, 0, t)
         return len(active)
 
     def _backlog(self) -> int:

@@ -15,16 +15,18 @@ from repro.core import (DynamicTuner, RuntimeSimulator, SimTaskSpec,
                         TaskRuntime, TunerConfig)
 from repro.core.sched.placement import ShardAffinePlacement
 from repro.core.taskgraph_apps import sim_matmul_specs
-from repro.core.trace import (AFFINITY_MISS, EV_ADMIT_DEFER, EV_CREATED,
-                              EV_DELEGATE, EV_DEPS, EV_END, EV_MSG_DRAIN,
-                              EV_MSG_ENQ, EV_QUIESCE, EV_READY, EV_START,
-                              EV_STEAL,
-                              INVERSION, NULL_TRACER, STARVATION,
-                              TASK_LIFECYCLE, Finding, TraceEvent,
-                              TraceRecorder, detect_affinity_misses,
-                              detect_all, detect_priority_inversion,
-                              detect_starvation, load_trace,
-                              replay_windows, save_trace)
+from repro.core.trace import (AFFINITY_MISS, COUNT_EMPTY_POLL,
+                              EV_ADMIT_DEFER, EV_CREATED, EV_DELEGATE,
+                              EV_DEPS, EV_END, EV_MSG_DRAIN, EV_MSG_ENQ,
+                              EV_QUIESCE, EV_READY, EV_SPAN, EV_START,
+                              EV_STEAL, INVERSION, NULL_TRACER, SPAN_ADMIT,
+                              SPAN_DISPATCH, SPAN_MANAGER, SPAN_READBACK,
+                              SPAN_TRACK, STARVATION, TASK_LIFECYCLE,
+                              Finding, TraceEvent, TraceRecorder,
+                              detect_affinity_misses, detect_all,
+                              detect_priority_inversion, detect_starvation,
+                              load_trace, replay_windows, save_trace,
+                              span_end)
 from repro.core.wd import DepMode, WorkDescriptor
 
 ALL_MODES = ("sync", "dast", "ddast", "sharded")
@@ -544,3 +546,196 @@ def test_traceview_slice_pairing_survives_dropped_starts():
     doc = to_chrome_trace(evs, "us")
     slices = [e for e in doc["traceEvents"] if e["ph"] == "X"]
     assert [s["name"] for s in slices] == ["ok"]
+
+
+# ------------------------------------------------- spans and the switch
+def test_recorder_spans_and_counts_round_trip(tmp_path):
+    clock = iter([2.0, 3.5, 9.0])
+    rec = TraceRecorder(2, clock=lambda: next(clock), time_unit="us")
+    t = rec.span(SPAN_MANAGER, 1, 1.0, 4)        # ends at 2.0
+    assert t == 2.0
+    rec.span(SPAN_TRACK, 0, t)                   # 2.0 -> 3.5, no payload
+    rec.count(COUNT_EMPTY_POLL, 1)
+    rec.count(COUNT_EMPTY_POLL, 1)
+    rec.count(COUNT_EMPTY_POLL, -1)              # overflow slot
+    p = tmp_path / "spans.trace"
+    rec.save(str(p))
+    events, meta = load_trace(str(p))
+    assert [(e.ev, e.label, e.slot, e.t, span_end(e), e.data[1])
+            for e in events] == [(EV_SPAN, SPAN_MANAGER, 1, 1.0, 2.0, 4),
+                                 (EV_SPAN, SPAN_TRACK, 0, 2.0, 3.5, None)]
+    assert meta["counts"] == {COUNT_EMPTY_POLL: [0, 2, 1]}
+    assert meta["origin"] is None                # a clock of its own
+
+
+def test_recorder_origin_maps_back_to_perf_counter():
+    before = time.perf_counter()
+    rec = TraceRecorder(1)
+    assert before <= rec.origin <= time.perf_counter()
+    rec.origin = time.perf_counter()
+    lo = time.perf_counter()
+    rec.span(SPAN_ADMIT, 0, rec.clock())
+    hi = time.perf_counter()
+    (e,) = rec.events()
+    assert lo <= rec.origin + e.t <= rec.origin + span_end(e) <= hi
+
+
+def test_recorder_switch_records_only_while_on():
+    with TaskRuntime(num_workers=2, mode="ddast", trace=True) as rt:
+        rt.tracer.enabled = False
+        for i in range(8):
+            rt.task(_spin, deps=[(("r", i % 2), "inout")], label="off")
+        rt.taskwait()
+        assert rt.tracer.total_appended == 0
+        rt.tracer.enabled = True
+        for i in range(8):
+            rt.task(_spin, deps=[(("r", i % 2), "inout")], label="on")
+        rt.taskwait()
+        rt.tracer.enabled = False
+        n_on = rt.tracer.total_appended
+        rt.task(_spin, label="off")
+        rt.taskwait()
+        assert rt.tracer.total_appended == n_on
+    labels = {e.label for e in rt.stats.events if e.wd_id >= 0}
+    assert labels == {"on"}
+    assert sum(e.ev == EV_END for e in rt.stats.events) == 8
+
+
+@pytest.mark.parametrize("producer", ["runtime", "engine"])
+def test_untraced_producers_record_nothing(producer):
+    if producer == "runtime":
+        with TaskRuntime(num_workers=2, mode="ddast") as rt:
+            for i in range(8):
+                rt.task(_spin, deps=[(("r", i % 2), "inout")])
+            rt.taskwait()
+        tracer = rt.tracer
+    else:
+        from test_scopes import _StubModel
+        from repro.serve.engine import Request, ServeEngine
+        eng = ServeEngine(_StubModel(), None, batch_slots=2, max_len=8,
+                          num_clients=1)
+        eng.submit(Request(prompt=[1, 2], max_new_tokens=2))
+        eng.run_until_drained()
+        assert eng.steps > 0
+        tracer = eng.tracer
+    assert tracer is NULL_TRACER
+    assert tracer.total_appended == 0 and tracer.counts() == {}
+
+
+def test_manager_spans_and_drains_on_the_managing_slot():
+    W = 3
+    with TaskRuntime(num_workers=W, mode="ddast", trace=True) as rt:
+        for i in range(60):
+            rt.task(_spin, deps=[(("r", i % 6), "inout")], label=f"t{i}")
+        rt.taskwait()
+        counts = rt.tracer.counts()
+    events = rt.stats.events
+    spans = [e for e in events if e.ev == EV_SPAN]
+    drains = [e for e in events if e.ev == EV_MSG_DRAIN]
+    assert spans and all(e.label == SPAN_MANAGER for e in spans)
+    # every slot is a real one: the workers' and the main thread's
+    assert {e.slot for e in spans} <= set(range(W + 1))
+    assert {e.slot for e in drains} <= set(range(W + 1))
+    # ddast drains only inside manager sessions: payloads add up
+    assert sum(e.data[1] for e in spans) == len(drains) == 120
+    for e in spans:
+        assert e.data[1] > 0 and span_end(e) >= e.t
+    # per slot, sessions and task bodies never overlap (a flat graph)
+    for s in range(W + 1):
+        mine = sorted((e.t, span_end(e)) for e in spans if e.slot == s)
+        starts = {e.wd_id: e.t for e in events
+                  if e.ev == EV_START and e.slot == s}
+        mine += sorted((starts[e.wd_id], e.t) for e in events
+                       if e.ev == EV_END and e.slot == s)
+        mine.sort()
+        assert all(a[1] <= b[0] for a, b in zip(mine, mine[1:])), s
+    assert sum(counts[COUNT_EMPTY_POLL]) > 0
+
+
+def test_starvation_backlog_reads_drains_on_worker_slots():
+    """Drains now carry the managing slot; the backlog signal counts
+    them by payload whatever the slot, as before."""
+    evs = _workers_present()
+    evs.append(_mk(1.0, EV_MSG_ENQ, slot=2, data=("submit_batch", 2, 10)))
+    evs.append(_mk(100.0, EV_MSG_DRAIN, slot=1,
+                   data=("submit_batch", 2, 10)))
+    found = detect_starvation(evs)
+    assert len(found) == 1 and found[0].detail["backlog_only"]
+    evs = _workers_present()
+    evs.append(_mk(0.5, EV_MSG_ENQ, slot=2, data=("submit_batch", 2, 20)))
+    t = 1.0
+    for _ in range(120):
+        evs.append(_mk(t, EV_MSG_ENQ, slot=2, data=("submit", 2, 1)))
+        evs.append(_mk(t + 0.25, EV_MSG_DRAIN, slot=0,
+                       data=("submit", 2, 1)))
+        t += 0.5
+    assert detect_starvation(evs) == []
+
+
+def test_serve_engine_step_spans():
+    from test_scopes import _StubModel
+    from repro.serve.engine import Request, ServeEngine
+    eng = ServeEngine(_StubModel(), None, batch_slots=2, max_len=8,
+                      num_clients=1, trace=True)
+    for _ in range(3):
+        eng.submit(Request(prompt=[1, 2], max_new_tokens=2))
+    eng.run_until_drained()
+    events = eng.tracer.events()
+    assert all(e.ev == EV_SPAN and e.slot == 0 for e in events)
+    names = [e.label for e in events]
+    assert names.count(SPAN_DISPATCH) == names.count(SPAN_READBACK) \
+        == names.count(SPAN_TRACK) == eng.steps
+    assert names.count(SPAN_ADMIT) >= eng.steps
+    assert sum(e.data[1] for e in events if e.label == SPAN_ADMIT) == 3
+    # a step's spans follow each other without a gap
+    for a, b in zip(events, events[1:]):
+        if b.label != SPAN_ADMIT:
+            assert (a.label, span_end(a)) == (
+                {SPAN_DISPATCH: SPAN_ADMIT, SPAN_READBACK: SPAN_DISPATCH,
+                 SPAN_TRACK: SPAN_READBACK}[b.label], b.t)
+
+
+def test_traceview_draws_spans_as_slices():
+    from repro.analysis.traceview import to_chrome_trace
+    evs = [_mk(1.0, EV_SPAN, slot=2, label=SPAN_MANAGER, data=(1.5, 3)),
+           _mk(2.0, EV_SPAN, slot=0, label=SPAN_TRACK, data=(2.25, None))]
+    doc = to_chrome_trace(evs, "s")
+    slices = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    assert [(s["name"], s["tid"], s["ts"], s["dur"], s["args"])
+            for s in slices] == [
+        (SPAN_MANAGER, 2, 1e6, 5e5, {"data": 3}),
+        (SPAN_TRACK, 0, 2e6, 2.5e5, {})]
+
+
+def test_recorder_events_while_producers_append():
+    """A live reader (the metrics sampler's sweep) merges the rings while
+    other threads append to them: no 'deque mutated' error, and every
+    event read is whole."""
+    import sys
+    import threading
+    rec = TraceRecorder(2, capacity=1 << 10)
+    stop = threading.Event()
+
+    def produce(slot):
+        while not stop.is_set():
+            rec.mgr_event(EV_MSG_ENQ, slot, ("submit", slot, 1))
+
+    threads = [threading.Thread(target=produce, args=(s % 3 - 1,))
+               for s in range(4)]
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        for t in threads:
+            t.start()
+        sweeps = 0
+        until = time.perf_counter() + 0.5
+        while time.perf_counter() < until:
+            assert all(e.data[0] == "submit" for e in rec.events())
+            sweeps += 1
+    finally:
+        stop.set()
+        sys.setswitchinterval(old)
+        for t in threads:
+            t.join(timeout=5)
+    assert not any(t.is_alive() for t in threads)
+    assert sweeps > 0 and rec.total_appended > 0
