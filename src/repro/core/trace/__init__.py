@@ -18,10 +18,10 @@ from .recorder import (COUNT_EMPTY_POLL, EV_ADMIT_DEFER, EV_COMBINE,
                        EV_START, EV_STEAL, EV_TIMEOUT_KILL, EV_TRACE_LOST,
                        EV_WORKER_LOST, FAULT_EVENTS, NULL_TRACER,
                        SPAN_ADMIT, SPAN_DISPATCH, SPAN_MANAGER,
-                       SPAN_READBACK, SPAN_TRACK, TASK_LIFECYCLE,
-                       NullTraceRecorder, TraceEvent, TraceRecorder,
-                       load_trace, replay_iterations_of, save_trace,
-                       span_end)
+                       SPAN_PREFILL, SPAN_READBACK, SPAN_TRACK,
+                       TASK_LIFECYCLE, NullTraceRecorder, TraceEvent,
+                       TraceRecorder, load_trace, replay_iterations_of,
+                       save_trace, span_end)
 
 __all__ = [
     "TraceRecorder", "NullTraceRecorder", "NULL_TRACER", "TraceEvent",
@@ -29,8 +29,8 @@ __all__ = [
     "EV_CREATED", "EV_DEPS", "EV_READY", "EV_START", "EV_END",
     "EV_MSG_ENQ", "EV_MSG_DRAIN", "EV_DELEGATE", "EV_COMBINE",
     "EV_STEAL", "EV_ADMIT_DEFER", "EV_QUIESCE", "EV_SPAN", "span_end",
-    "SPAN_MANAGER", "SPAN_ADMIT", "SPAN_DISPATCH", "SPAN_READBACK",
-    "SPAN_TRACK", "COUNT_EMPTY_POLL",
+    "SPAN_MANAGER", "SPAN_ADMIT", "SPAN_PREFILL", "SPAN_DISPATCH",
+    "SPAN_READBACK", "SPAN_TRACK", "COUNT_EMPTY_POLL",
     "EV_WORKER_LOST", "EV_RESPAWN", "EV_RETRY", "EV_TIMEOUT_KILL",
     "EV_SCOPE_EXPIRED", "EV_TRACE_LOST", "FAULT_EVENTS",
     "Finding", "IncrementalDetector", "detect_all", "detect_starvation",

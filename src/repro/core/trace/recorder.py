@@ -94,6 +94,8 @@ SPAN_MANAGER = "manager"          # one manager session that processed
 #                                   messages; payload: how many
 SPAN_ADMIT = "admit"              # serving engine: admission, with the
 #                                   slot-cache resets; payload: admitted
+SPAN_PREFILL = "prefill"          # serving engine: a prompt chunk's
+#                                   upload and launch; payload: its tokens
 SPAN_DISPATCH = "dispatch"        # serving engine: uploads + step launch
 SPAN_READBACK = "readback"        # serving engine: waiting for the
 #                                   step's tokens on the host

@@ -25,16 +25,19 @@ def _use_pallas() -> bool:
 def attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
               causal: bool = False, window: Optional[int] = None,
               kv_len: Optional[jax.Array] = None,
-              softcap: Optional[float] = None) -> jax.Array:
+              softcap: Optional[float] = None,
+              q_offset: Optional[jax.Array] = None) -> jax.Array:
     """GQA attention; see kernels.ref.attention_ref for the contract."""
     s = q.shape[1]
-    if _use_pallas() and s > 1 and kv_len is None and q.shape[1] == k.shape[1]:
+    if _use_pallas() and s > 1 and kv_len is None and q_offset is None \
+            and q.shape[1] == k.shape[1]:
         from .flash_attention import flash_attention
         interpret = jax.default_backend() != "tpu"
         return flash_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap, interpret=interpret)
     return _ref.attention_ref(q, k, v, causal=causal, window=window,
-                              kv_len=kv_len, softcap=softcap)
+                              kv_len=kv_len, softcap=softcap,
+                              q_offset=q_offset)
 
 
 def ssm_scan(a: jax.Array, bx: jax.Array,

@@ -15,7 +15,8 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
                   causal: bool = False, window: Optional[int] = None,
                   kv_len: Optional[jax.Array] = None,
                   softcap: Optional[float] = None,
-                  scale: Optional[float] = None) -> jax.Array:
+                  scale: Optional[float] = None,
+                  q_offset: Optional[jax.Array] = None) -> jax.Array:
     """GQA attention oracle.
 
     q [B,S,nq,hd]; k/v [B,T,nkv,hd] with nq % nkv == 0.
@@ -23,6 +24,8 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
     window     — additionally restrict to a trailing sliding window
     kv_len     — scalar or [B]: only keys < kv_len are valid (decode)
     softcap    — tanh softcapping of attention logits (Gemma-2)
+    q_offset   — scalar: queries sit at positions q_offset..q_offset+S-1
+                 instead of T-S..T-1 (a prefill chunk against a cache row)
     """
     b, s, nq, hd = q.shape
     t, nkv = k.shape[1], k.shape[2]
@@ -46,7 +49,8 @@ def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
             valid &= kpos[None, :] > (kv[:, None] - 1) - window
         m5 = valid[:, None, None, None, :]           # [B,1,1,1,T]
     else:
-        qpos = jnp.arange(s) + (t - s)   # align query block to seq end
+        # align the query block to the sequence end, or to q_offset
+        qpos = jnp.arange(s) + (t - s if q_offset is None else q_offset)
         mask = jnp.ones((s, t), bool)
         if causal:
             mask &= kpos[None, :] <= qpos[:, None]

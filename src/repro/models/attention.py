@@ -1,7 +1,8 @@
 """Grouped-query attention with the features the assigned pool needs:
 GQA (any nq/nkv ratio), optional QKV bias (Qwen2), sliding-window local
 attention + attn-logit softcapping (Gemma-2), cross-attention (Whisper),
-RoPE or NoPE. Train path and single-token decode path with KV cache.
+RoPE or NoPE. Train path, single-token decode path with KV cache, and a
+chunk-prefill path that writes a chunk of one slot's prompt into it.
 
 The inner attention math routes through `repro.kernels.ops.attention`,
 which dispatches to the Pallas flash kernel on TPU and to the pure-jnp
@@ -142,6 +143,41 @@ def attention_decode(cfg: ModelConfig, p: Params, x: jax.Array,
                        window=cfg.sliding_window if local else None,
                        softcap=cfg.attn_softcap)
     return o.reshape(b, 1, -1) @ p["wo"], {"k": k, "v": v}
+
+
+def attention_prefill(cfg: ModelConfig, p: Params, x: jax.Array,
+                      row: Params, start: jax.Array, local: bool = False
+                      ) -> Tuple[jax.Array, Params]:
+    """One prefill chunk of one slot. x [1,C,d] holds positions
+    start..start+C-1; row k/v [L,nkv,hd] is the slot's cache row, filled
+    below `start`. Returns (out [1,C,d], {"k","v"} [C,nkv,hd]): the row's
+    C positions from min(start, L-C) with the chunk written in, so a
+    caller storing them there never has them shifted by a clamped
+    update."""
+    c = x.shape[1]
+    hd = cfg.resolved_head_dim
+    q = _project_q(cfg, p, x)                        # [1,C,nq,hd]
+    kn, vn = _project_kv(cfg, p, x)                  # [1,C,nkv,hd]
+    cos, sin = rope_cos_sin(start + jnp.arange(c), hd, cfg.rope_theta)
+    q = apply_rope(q, cos, sin)
+    kn = apply_rope(kn, cos, sin)
+    max_len = row["k"].shape[0]
+
+    def insert(r, new):
+        # padded by C so that the chunk lands at `start` unclamped; the
+        # padding is visible only to queries past the prompt
+        r = jnp.concatenate([r, jnp.zeros((c,) + r.shape[1:], r.dtype)])
+        return jax.lax.dynamic_update_slice_in_dim(r, new[0].astype(r.dtype),
+                                                   start, axis=0)
+
+    k, v = insert(row["k"], kn), insert(row["v"], vn)
+    o = kops.attention(q, k[None], v[None], causal=True, q_offset=start,
+                       window=cfg.sliding_window if local else None,
+                       softcap=cfg.attn_softcap)
+    at = jnp.minimum(start, max_len - c)
+    return o.reshape(1, c, -1) @ p["wo"], {
+        "k": jax.lax.dynamic_slice_in_dim(k, at, c, axis=0),
+        "v": jax.lax.dynamic_slice_in_dim(v, at, c, axis=0)}
 
 
 def precompute_cross_kv(cfg: ModelConfig, p: Params,

@@ -22,6 +22,9 @@ class ModelAPI:
     forward: Callable[..., Tuple[jax.Array, jax.Array]]
     decode_step: Callable[..., Tuple[jax.Array, Params]]
     init_cache: Callable[[int, int], Params]
+    # (params, cache, tokens [C], slot, start, n_valid) -> (token, cache);
+    # None where a chunk would not give what one-token decode gives
+    prefill_chunk: Optional[Callable[..., Tuple[jax.Array, Params]]] = None
 
 
 def get_model(cfg: ModelConfig) -> ModelAPI:
@@ -45,6 +48,11 @@ def get_model(cfg: ModelConfig) -> ModelAPI:
             transformer.decode_step(cfg, params, cache, tokens, pos),
         init_cache=lambda batch, max_len:
             transformer.init_cache(cfg, batch, max_len),
+        prefill_chunk=(
+            (lambda params, cache, tokens, slot, start, n_valid:
+             transformer.prefill_chunk(cfg, params, cache, tokens, slot,
+                                       start, n_valid))
+            if transformer.chunk_prefill_exact(cfg) else None),
     )
 
 

@@ -191,9 +191,76 @@ def decode_step(cfg: ModelConfig, params: Params, cache: Params,
     return logits[:, 0], new_cache
 
 
+def chunk_prefill_exact(cfg: ModelConfig) -> bool:
+    """Whether `prefill_chunk` gives what one-token decode gives: every
+    mixer is attention (a recurrent state would need a chunked scan) and
+    every FFN dense (MoE capacity drops tokens in a chunk, never in a
+    one-token step)."""
+    return all(b.mixer in ("attn", "attn_local") and b.ffn in ("mlp", "none")
+               for b in cfg.pattern)
+
+
+def apply_block_prefill(cfg: ModelConfig, bspec: BlockSpec, p: Params,
+                        x: jax.Array, row: Params, start: jax.Array
+                        ) -> Tuple[jax.Array, Params]:
+    h = apply_norm(cfg, p["norm_mixer"], x)
+    h, kv = attn.attention_prefill(cfg, p["mixer"], h, row, start,
+                                   local=(bspec.mixer == "attn_local"))
+    if cfg.post_norm:
+        h = apply_norm(cfg, p["post_norm_mixer"], h)
+    x = x + h
+    if bspec.ffn != "none":
+        h = apply_mlp(cfg, p["ffn"], apply_norm(cfg, p["norm_ffn"], x))
+        if cfg.post_norm:
+            h = apply_norm(cfg, p["post_norm_ffn"], h)
+        x = x + h
+    return x, kv
+
+
+def prefill_chunk(cfg: ModelConfig, params: Params, cache: Params,
+                  tokens: jax.Array, slot: jax.Array, start: jax.Array,
+                  n_valid: jax.Array) -> Tuple[jax.Array, Params]:
+    """Run C prompt tokens of one slot through the model and write their
+    K/V into the cache. tokens [C] sit at positions start..start+C-1, the
+    first n_valid of them real; slot, start and n_valid are int32
+    scalars, so one program serves every slot, offset and length. The
+    slot's rows below `start` must hold the prompt's earlier chunks.
+    Returns (the greedy token after position start+n_valid-1, cache).
+    Only for configs with `chunk_prefill_exact`."""
+    c = tokens.shape[0]
+    x = embed_tokens(cfg, params["embed"], tokens[None])
+    rows = jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, slot, 1, keepdims=False),
+        cache)                                       # [R, L, nkv, hd]
+
+    def step_fn(x, slices):
+        layer_slice, row_slice = slices
+        kvs = []
+        for p_, bspec in enumerate(cfg.pattern):
+            x, kv = apply_block_prefill(cfg, bspec, layer_slice[p_], x,
+                                        row_slice[p_], start)
+            kvs.append(kv)
+        return x, tuple(kvs)
+
+    x, kvs = jax.lax.scan(step_fn, x, (params["layers"], rows))
+    # kvs: [R, C, nkv, hd], the rows' C positions from min(start, L - C)
+    at = jnp.minimum(start, cache[0]["k"].shape[2] - c)
+    zero = jnp.zeros((), jnp.int32)
+    cache = jax.tree.map(
+        lambda a, new: jax.lax.dynamic_update_slice(
+            a, new[:, None].astype(a.dtype), (zero, slot, at, zero, zero)),
+        cache, kvs)
+    last = jax.lax.dynamic_slice_in_dim(x, n_valid - 1, 1, axis=1)
+    logits = unembed(cfg, params["embed"],
+                     apply_norm(cfg, params["final_norm"], last))
+    # over the real vocabulary only, as the decode step's argmax
+    tok = jnp.argmax(logits[0, 0, :cfg.vocab_size]).astype(jnp.int32)
+    return tok, cache
+
+
 def prefill(cfg: ModelConfig, params: Params, tokens: jax.Array
             ) -> Tuple[jax.Array, jax.Array]:
     """Prefill = teacher-forced forward over the prompt; returns logits.
-    (Cache-filling prefill exists in serve/serve_step.py; for the
-    prefill_32k dry-run cell the compute-equivalent forward is lowered.)"""
+    (Cache-filling prefill is `prefill_chunk`; for the prefill_32k
+    dry-run cell the compute-equivalent forward is lowered.)"""
     return forward(cfg, params, tokens)

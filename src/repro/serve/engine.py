@@ -7,10 +7,21 @@ calling client's own SPSC queue (core.queues). The engine loop plays the
 DDAST manager: it drains client queues — round-robin, up to
 MAX_OPS_THREAD per client, stopping early once MIN_READY (free-slot fill)
 is reached — admits requests into batch slots, and every engine step
-advances ALL active slots by one token with a single batched
-`decode_step` (prompt tokens are teacher-forced through the decode path;
-generated tokens continue it). Slots free as requests finish => true
-continuous batching with per-slot positions.
+advances ALL decoding slots by one token with a single batched
+`decode_step`. Slots free as requests finish => true continuous batching
+with per-slot positions.
+
+Prompts reach the cache one of two ways. Where the model has
+``prefill_chunk`` (attention mixers, dense FFNs), an admitted slot joins
+a FIFO of prompts awaiting prefill, and each step first runs the FIFO
+head's next chunk of ``PREFILL_CHUNK`` tokens: one jitted call, the
+cache donated, that writes the chunk's K/V into the slot's cache rows.
+The slot's decode lane is ignored until its prompt is in the cache; the
+last chunk's greedy token is its first output, read back with that
+step's decode tokens, and the slot decodes from the next step on.
+Otherwise (recurrent mixers, MoE FFNs, models without the function) the
+prompt is teacher-forced through the decode step, a token a step, after
+the slot's cache lanes are zeroed.
 
 With ``runtime=`` (a multi-tenant ``TaskRuntime(num_clients>=1)``) each
 client queue becomes a :class:`~repro.core.scopes.JobScope` on the REAL
@@ -25,11 +36,13 @@ engines number their requests independently.
 
 With ``trace=True`` the engine owns a one-slot
 :class:`~repro.core.trace.TraceRecorder` (``engine.tracer``) and each
-step records four spans on it: ``admit`` (draining and admission, the
-slot-cache resets included; payload: requests admitted), ``dispatch``
-(the token and position uploads and the step's launch), ``readback``
-(the host waiting for the step's tokens) and ``track`` (the per-slot
-loop). Switch ``engine.tracer.enabled`` to record one stretch of a run.
+step records its spans on it: ``admit`` (draining and admission, the
+slot-cache resets included; payload: requests admitted), ``prefill``
+(on steps that run a chunk: its upload and launch; payload: the chunk's
+prompt tokens), ``dispatch`` (the token and position uploads and the
+decode step's launch), ``readback`` (the host waiting for the step's
+tokens) and ``track`` (the per-slot loop). Switch
+``engine.tracer.enabled`` to record one stretch of a run.
 """
 from __future__ import annotations
 
@@ -48,8 +61,29 @@ from ..core.metrics import LogHistogram, prometheus_text
 from ..core.queues import WorkerQueues
 from ..core.sched import DagNode, bottom_levels, build_arrays
 from ..core.trace import (NULL_TRACER, SPAN_ADMIT, SPAN_DISPATCH,
-                          SPAN_READBACK, SPAN_TRACK, TraceRecorder)
+                          SPAN_PREFILL, SPAN_READBACK, SPAN_TRACK,
+                          TraceRecorder)
 from ..models.registry import ModelAPI
+
+# prompt tokens one prefill call takes (fewer where max_len is shorter).
+# On one TPU v5e (qwen2-0.5b, 128 slots x 1024) a 512-token chunk costs
+# ~4 ms beside a ~36 ms decode step; 256 needs more chunks, so more steps,
+# before a long prompt's first token, and 128 overruns one chunk a step.
+PREFILL_CHUNK = 512
+
+
+def prefill_program(model: ModelAPI):
+    """The engine's chunk prefill, jitted with the cache donated, or None
+    where the model has no ``prefill_chunk``. Its device program is
+    ``jit_prefill_chunk``, apart from the decode step's
+    ``jit_serve_step``."""
+    chunk_fn = getattr(model, "prefill_chunk", None)
+    if chunk_fn is None:
+        return None
+
+    def prefill_chunk(params, cache, tokens, slot, start, n_valid):
+        return chunk_fn(params, cache, tokens, slot, start, n_valid)
+    return jax.jit(prefill_chunk, donate_argnums=(1,))
 
 
 @dataclass
@@ -72,7 +106,7 @@ class Request:
 class _Slot:
     req: Optional[Request] = None
     pos: int = 0                    # next cache position
-    prompt_left: int = 0
+    prompt_left: int = 0            # prompt tokens not yet in the cache
 
     @property
     def free(self) -> bool:
@@ -131,9 +165,14 @@ class ServeEngine:
         self._pos = np.zeros((self.B,), np.int32)
         from ..train.train_step import make_serve_step
         self._step_fn = jax.jit(make_serve_step(model))
+        self._chunk = min(PREFILL_CHUNK, max_len)
+        self._prefill_fn = prefill_program(model)
+        self._prefillq: deque = deque()     # slots awaiting prefill, FIFO
         self.steps = 0
         self.completed: List[Request] = []
-        self.stats = {"admitted": 0, "drained_msgs": 0, "callback_passes": 0}
+        self.stats = {"admitted": 0, "drained_msgs": 0, "callback_passes": 0,
+                      "prefill_chunks": 0, "prefill_tokens": 0,
+                      "teacher_forced_tokens": 0}
         # per-client admitted->finished latency in engine steps (the
         # serving-layer unit: one step = one batched decode); recorded
         # only on the engine-step thread, so plain histograms suffice
@@ -272,9 +311,14 @@ class ServeEngine:
                 slot.pos = 0
                 slot.prompt_left = len(req.prompt)
                 req.admitted_step = self.steps
-                self._tokens[i] = req.prompt[0]
                 self._pos[i] = 0
-                self._reset_slot_cache(i)
+                if self._prefill_fn is not None:
+                    # no reset: rows past the prompt are written by the
+                    # decode step before kv_len lets it read them
+                    self._prefillq.append(i)
+                else:
+                    self._tokens[i] = req.prompt[0]
+                    self._reset_slot_cache(i)
                 self.stats["admitted"] += 1
                 return
         raise RuntimeError("no free slot")
@@ -289,8 +333,9 @@ class ServeEngine:
 
     # ----------------------------------------------------------- stepping
     def step(self) -> int:
-        """One engine iteration: drain client queues (manager), then one
-        batched decode step. Returns number of active slots advanced."""
+        """One engine iteration: drain client queues (manager), run the
+        prefill FIFO head's next chunk, then one batched decode step.
+        Returns number of occupied slots advanced."""
         tr = self.tracer
         on = tr.enabled
         if on:
@@ -302,40 +347,84 @@ class ServeEngine:
         active = [i for i, s in enumerate(self.slots) if not s.free]
         if not active:
             return 0
-        next_tok, _, self.cache = self._step_fn(
-            self.params, self.cache, jnp.asarray(self._tokens),
-            jnp.asarray(self._pos))
+        chunked = self._prefill_fn is not None
+        lanes = [i for i in active
+                 if not (chunked and self.slots[i].prompt_left)]
+        prefilled, first_tok = -1, None
+        if self._prefillq:
+            n, prefilled, first_tok = self._prefill_next()
+            if on:
+                t = tr.span(SPAN_PREFILL, 0, t, n)
+        next_tok = None
+        if lanes:
+            next_tok, _, self.cache = self._step_fn(
+                self.params, self.cache, jnp.asarray(self._tokens),
+                jnp.asarray(self._pos))
         if on:
             t = tr.span(SPAN_DISPATCH, 0, t)
-        next_tok = np.asarray(next_tok)
+        next_tok, first_tok = jax.device_get((next_tok, first_tok))
         if on:
             t = tr.span(SPAN_READBACK, 0, t)
         self.steps += 1
-        for i in active:
+        for i in lanes:
             slot = self.slots[i]
-            req = slot.req
             slot.pos += 1
-            slot.prompt_left -= 1
-            if slot.prompt_left > 0:
-                self._tokens[i] = req.prompt[slot.pos]      # teacher-force
-            else:
-                tok = int(next_tok[i])
-                req.output.append(tok)
-                self._tokens[i] = tok
-                if len(req.output) >= req.max_new_tokens or \
-                        tok == self.eos_id or slot.pos + 1 >= self.max_len:
-                    req.finished_step = self.steps
-                    if 0 <= req.client_id < len(self._client_latency):
-                        self._client_latency[req.client_id].record(
-                            req.finished_step - req.admitted_step)
-                    req.done_event.set()
-                    self.completed.append(req)
-                    slot.req = None
+            if slot.prompt_left:        # teacher-forced through decode
+                slot.prompt_left -= 1
+                self.stats["teacher_forced_tokens"] += 1
+                if slot.prompt_left:
+                    self._tokens[i] = slot.req.prompt[slot.pos]
+                    self._pos[i] = slot.pos
                     continue
-            self._pos[i] = slot.pos
+            self._emit(i, int(next_tok[i]))
+        if first_tok is not None:
+            self._emit(prefilled, int(first_tok))
         if on:
             tr.span(SPAN_TRACK, 0, t)
         return len(active)
+
+    def _prefill_next(self):
+        """Launch the FIFO head's next prompt chunk into its cache rows.
+        Returns (the chunk's prompt tokens, slot, the slot's first token
+        on the device) after its last chunk, else (tokens, -1, None).
+        Until then the slot's decode position is where its next chunk
+        starts, so the decode step's write there is overwritten before
+        anything reads it."""
+        i = self._prefillq[0]
+        slot = self.slots[i]
+        n = min(self._chunk, slot.prompt_left)
+        tokens = np.zeros((self._chunk,), np.int32)
+        tokens[:n] = slot.req.prompt[slot.pos:slot.pos + n]
+        tok, self.cache = self._prefill_fn(
+            self.params, self.cache, tokens, np.int32(i),
+            np.int32(slot.pos), np.int32(n))
+        slot.pos += n
+        slot.prompt_left -= n
+        self._pos[i] = slot.pos
+        self.stats["prefill_chunks"] += 1
+        self.stats["prefill_tokens"] += n
+        if slot.prompt_left:
+            return n, -1, None
+        self._prefillq.popleft()
+        return n, i, tok
+
+    def _emit(self, i: int, tok: int) -> None:
+        """Append slot i's next output token; free the slot if done."""
+        slot = self.slots[i]
+        req = slot.req
+        req.output.append(tok)
+        self._tokens[i] = tok
+        if len(req.output) >= req.max_new_tokens or \
+                tok == self.eos_id or slot.pos + 1 >= self.max_len:
+            req.finished_step = self.steps
+            if 0 <= req.client_id < len(self._client_latency):
+                self._client_latency[req.client_id].record(
+                    req.finished_step - req.admitted_step)
+            req.done_event.set()
+            self.completed.append(req)
+            slot.req = None
+            return
+        self._pos[i] = slot.pos
 
     def _backlog(self) -> int:
         """Requests not yet in a batch slot: client queues, plus (when

@@ -1,6 +1,7 @@
-"""Batched serving steps: cache-filling prefill (decode scan over the
-prompt) + sampling decode. These are the jit'd device functions the
-engine and the decode dry-run cells lower."""
+"""Offline greedy serving: cache-filling prefill by a decode scan over
+the prompt, then greedy decode. The tests' oracle for the engine (whose
+own prompt paths are `transformer.prefill_chunk` and, for recurrent and
+MoE models, teacher-forcing through its decode step)."""
 from __future__ import annotations
 
 from typing import Any, Tuple

@@ -123,3 +123,34 @@ def test_qwen2_0_5b_train_step_fits_one_chip(one_chip, no_persistent_cache,
     assert m.alias_size_in_bytes > 0.9 * m.argument_size_in_bytes
     assert live < 16e9
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_qwen2_0_5b_prefill_chunk_donates_cache(one_chip, no_persistent_cache):
+    """The serving engine's chunk prefill at the chat cell's shapes (128
+    slots x 1024): the cache is updated in place, and the program's name
+    stays apart from the decode step's."""
+    from repro.configs import get_config
+    from repro.models.registry import get_model
+    from repro.serve.engine import PREFILL_CHUNK, prefill_program
+
+    model = get_model(get_config("qwen2-0.5b"))
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = on_chip(jax.eval_shape(model.init_params, jax.random.key(0)))
+    cache = on_chip(jax.eval_shape(lambda: model.init_cache(128, 1024)))
+    i32 = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    tokens = jax.ShapeDtypeStruct((PREFILL_CHUNK,), jnp.int32,
+                                  sharding=one_chip)
+    compiled = prefill_program(model).lower(params, cache, tokens, i32, i32,
+                                            i32).compile()
+    m = compiled.memory_analysis()
+    cache_bytes = sum(a.size * a.dtype.itemsize
+                      for a in jax.tree.leaves(cache))
+    assert m.alias_size_in_bytes == cache_bytes
+    assert m.temp_size_in_bytes < 0.05 * cache_bytes
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_prefill_chunk")
+    assert "jit_serve_step" not in text

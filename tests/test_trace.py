@@ -20,8 +20,9 @@ from repro.core.trace import (AFFINITY_MISS, COUNT_EMPTY_POLL,
                               EV_DEPS, EV_END, EV_MSG_DRAIN, EV_MSG_ENQ,
                               EV_QUIESCE, EV_READY, EV_SPAN, EV_START,
                               EV_STEAL, INVERSION, NULL_TRACER, SPAN_ADMIT,
-                              SPAN_DISPATCH, SPAN_MANAGER, SPAN_READBACK,
-                              SPAN_TRACK, STARVATION, TASK_LIFECYCLE,
+                              SPAN_DISPATCH, SPAN_MANAGER, SPAN_PREFILL,
+                              SPAN_READBACK, SPAN_TRACK, STARVATION,
+                              TASK_LIFECYCLE,
                               Finding, TraceEvent, TraceRecorder,
                               detect_affinity_misses, detect_all,
                               detect_priority_inversion, detect_starvation,
@@ -687,12 +688,39 @@ def test_serve_engine_step_spans():
         == names.count(SPAN_TRACK) == eng.steps
     assert names.count(SPAN_ADMIT) >= eng.steps
     assert sum(e.data[1] for e in events if e.label == SPAN_ADMIT) == 3
+    # the stub has no prefill_chunk: its prompts are teacher-forced
+    assert SPAN_PREFILL not in names
+    assert eng.stats["teacher_forced_tokens"] == 6
     # a step's spans follow each other without a gap
     for a, b in zip(events, events[1:]):
         if b.label != SPAN_ADMIT:
             assert (a.label, span_end(a)) == (
                 {SPAN_DISPATCH: SPAN_ADMIT, SPAN_READBACK: SPAN_DISPATCH,
                  SPAN_TRACK: SPAN_READBACK}[b.label], b.t)
+
+
+def test_serve_engine_prefill_spans():
+    import jax
+    from repro.configs import tiny_config
+    from repro.models.registry import get_model
+    from repro.serve.engine import Request, ServeEngine
+    model = get_model(tiny_config("qwen2-0.5b"))
+    eng = ServeEngine(model, model.init_params(jax.random.key(0)),
+                      batch_slots=2, max_len=16, num_clients=1, trace=True)
+    for n in (3, 16, 9):
+        eng.submit(Request(prompt=list(range(1, n + 1)), max_new_tokens=2))
+    eng.run_until_drained()
+    events = eng.tracer.events()
+    prefill = [e for e in events if e.label == SPAN_PREFILL]
+    # max_len 16 caps the chunk at 16 tokens: one chunk a prompt
+    assert len(prefill) == eng.stats["prefill_chunks"] == 3
+    assert sum(e.data[1] for e in prefill) \
+        == eng.stats["prefill_tokens"] == 28
+    # each chunk's span sits between the step's admission and dispatch
+    for a, b, c in zip(events, events[1:], events[2:]):
+        if b.label == SPAN_PREFILL:
+            assert (a.label, c.label) == (SPAN_ADMIT, SPAN_DISPATCH)
+            assert span_end(a) == b.t and span_end(b) == c.t
 
 
 def test_traceview_draws_spans_as_slices():
