@@ -30,6 +30,7 @@ class Run:
     traffic: dict                # traffic/<traffic>.json
     limits: dict                 # limits/<cell>.json
     reference: Any               # configs/<config>.py, loaded
+    arch: Any                    # archs/<architecture>.py, loaded, or None
     devices: List[Any]
     peaks: Any                   # device.Peaks
     root: Path                   # the checkout

@@ -25,8 +25,7 @@ from typing import List, Optional
 from .. import traffic as gen
 from ..common import Outcome, Run, Window, percentile, span
 from ..device import memory_peak_bytes
-from ..flops import Decoder
-from ..program import model_config, program_params
+from ..weights import generate
 
 
 @dataclass
@@ -36,6 +35,7 @@ class Tracked:
     sent: float = 0.0
     admitted: float = -1.0        # end of the step that admitted it
     tokens: List[float] = field(default_factory=list)   # token times
+    token_steps: List[int] = field(default_factory=list)  # and engine steps
     done: bool = False
 
 
@@ -114,23 +114,28 @@ def run(r: Run) -> Outcome:
     from repro.models.registry import get_model
     from repro.serve.engine import Request, ServeEngine
 
-    c, tr = r.config, r.traffic
-    dec = Decoder.from_config(c)
-    model = get_model(model_config(c))
-    params = program_params(model, c, r.seed)
+    c, tr, arch = r.config, r.traffic, r.arch
+    dec = arch.counts(c)
+    model = get_model(arch.model_config(c))
+    params = generate(arch.weight_shapes(c), r.seed, c["torch_dtype"],
+                      convert=lambda w: arch.to_program(model, w))
     jax.block_until_ready(params)
     r.mark("weights")
     engine = ServeEngine(model, params, batch_slots=tr["slots"],
                          max_len=tr["max_len"], num_clients=tr["clients"])
     jax.block_until_ready(engine.cache)
     r.mark("engine")
-    # warm every program the window drives: the step, the per-admission
-    # cache reset, the token upload
+    # warm every program the window drives: the decode step, the prompt
+    # chunk (or, for a model without one, the per-admission cache reset),
+    # the token upload
     warm = engine.submit(Request(prompt=[1, 2], max_new_tokens=2))
     while not warm.done_event.is_set():
         engine.step()
     jax.block_until_ready(engine.cache)
     warm_steps = engine.steps
+    # prompts go through the chunk program, else are teacher-forced
+    # through the decode step a token a step
+    chunked = engine.stats["prefill_chunks"] > 0
     r.mark("warm")
 
     warmup_s, drain_s = float(tr["warmup_s"]), float(tr["drain_limit_s"])
@@ -145,6 +150,7 @@ def run(r: Run) -> Outcome:
                        if warmup_s <= a.due_s < warmup_s + r.seconds)
     compiles0 = r.compiles.count
     win_steps = [None, None]
+    win_stats = [None, None]          # the engine's counters at both ends
     trace_at = ws + min(5.0, r.seconds / 4)
     trace_s = min(3.0, r.seconds / 4)
     window = Window(r) if r.trace else None
@@ -162,9 +168,11 @@ def run(r: Run) -> Outcome:
             now = clock.now()
             if win_steps[0] is None and now >= ws:
                 win_steps[0] = engine.steps
+                win_stats[0] = dict(engine.stats)
             if now >= we:
                 if win_steps[1] is None:
                     win_steps[1] = engine.steps
+                    win_stats[1] = dict(engine.stats)
                     compiles_window = r.compiles.count - compiles0
                 w_sent = len(window_reqs)
                 if (w_sent == n_due_window
@@ -206,6 +214,7 @@ def run(r: Run) -> Outcome:
                     k = len(x.req.output)
                     while len(x.tokens) < k:
                         x.tokens.append(t)
+                        x.token_steps.append(engine.steps)
                     if x.req.finished_step >= 0:
                         x.done = True
                     else:
@@ -226,6 +235,7 @@ def run(r: Run) -> Outcome:
     reduced = window.reduce() if window is not None else None
     if win_steps[1] is None:
         win_steps[1] = engine.steps
+        win_stats[1] = dict(engine.stats)
         compiles_window = r.compiles.count - compiles0
     lateness = [x.sent - x.due for x in g.sent]
     peak = memory_peak_bytes(r.devices)
@@ -239,30 +249,20 @@ def run(r: Run) -> Outcome:
            "ttft_p95_ms": 1e3 * percentile(ttft, 95) if ttft else float("nan"),
            "itl_p95_ms": 1e3 * percentile(itl, 95) if itl else float("nan")}
 
-    # ---- per-layer inputs: slot-steps of the window, the traced steps
+    # ---- per-layer inputs: the work of the decode steps traced
     s0, s1 = win_steps
-    everyone = [x for x in g.sent if x.req.admitted_step >= 0]
-    prefill = total = 0
-    for x in everyone:
-        a = x.req.admitted_step
-        p_len = len(x.req.prompt)
-        last = a + p_len + x.req.max_new_tokens - 1
-        total += max(0, min(last, s1) - max(a + 1, s0 + 1) + 1)
-        prefill += max(0, min(a + p_len, s1) - max(a + 1, s0 + 1) + 1)
-    layer = {"slot_steps": total, "prefill_slot_steps": prefill,
+    layer = {"prefill_chunked": chunked,
              "admit_wait_s": [x.admitted - x.due for x in window_reqs
                               if x.admitted >= 0],
              "serve_steps_traced": None, "serve_flops_traced": None}
     if traced_steps[0] is not None:
         k0, k1 = traced_steps
-        ctx = []
-        for x in everyone:
-            a = x.req.admitted_step
-            last = a + len(x.req.prompt) + x.req.max_new_tokens - 1
-            for k in range(max(a + 1, k0 + 1), min(last, k1) + 1):
-                ctx.append(k - a)
         layer["serve_steps_traced"] = k1 - k0
-        layer["serve_flops_traced"] = dec.decode_step_flops(ctx)
+        layer["serve_flops_traced"] = dec.decode_step_flops(
+            decode_contexts(g.sent, chunked, k0, k1))
+    w0, w1 = win_stats
+    prompt_path = {k: w1[k] - w0[k] for k in (
+        "prefill_chunks", "prefill_tokens", "teacher_forced_tokens")}
 
     notes = {
         "window": f"{len(window_reqs)} requests due ({n_due_window} "
@@ -270,6 +270,7 @@ def run(r: Run) -> Outcome:
                   f"{s1 - s0} in the window, {engine.steps} in all "
                   f"({warm_steps} warm-up)",
         "compiles_in_window": compiles_window,
+        "prompt_path_in_window": prompt_path,
         "profiler_stop_s": f"{stop_s:.3f} (the clock paused)",
         "longest_loop_pass_ms": f"{1e3 * longest[0]:.1f}, ending "
                                 f"{longest[1]:.1f} s after set-up",
@@ -286,7 +287,7 @@ def run(r: Run) -> Outcome:
     requests = [(list(x.req.prompt), list(x.req.output)) for x in sample]
     bad_ids = sum(1 for _, out in requests for t in out
                   if not 0 <= t < c["vocab_size"])
-    del engine, params, g, pending, active, everyone
+    del engine, params, g, pending, active
     gc.collect()
     checks = {"bad_ids": (float(bad_ids), 0.0)}
     control = {}
@@ -306,6 +307,25 @@ def run(r: Run) -> Outcome:
     return Outcome(e2e=e2e, attempted=len(window_reqs), failed=len(missing),
                    checks=checks, memory_peak_bytes=peak, layer=layer,
                    reduced=reduced, control=control, notes=notes)
+
+
+def decode_contexts(sent: List[Tracked], chunked: bool, k0: int,
+                    k1: int) -> List[int]:
+    """The context (positions attended) of each token the decode step
+    ran in engine steps k0+1..k1: output token j of a P-token prompt at
+    P + j. Where prompts are chunked, a request's first token came from
+    its last chunk and is left out; where they are teacher-forced, prompt
+    token j ran at step admitted_step + 1 + j at context j + 1 (the last
+    one also made output token 0)."""
+    ctx = []
+    for x in sent:
+        p = len(x.req.prompt)
+        ctx += [p + j for j, k in enumerate(x.token_steps)
+                if k0 < k <= k1 and (j or not chunked)]
+        if not chunked and x.req.admitted_step >= 0:
+            a = x.req.admitted_step
+            ctx += [j + 1 for j in range(p - 1) if k0 < a + 1 + j <= k1]
+    return ctx
 
 
 def pick_sample(done: List[Tracked], n: int, seed: int) -> List[Tracked]:

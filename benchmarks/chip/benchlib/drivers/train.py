@@ -23,9 +23,7 @@ from collections import deque
 from .. import traffic as gen
 from ..common import Outcome, Run, Window, span
 from ..device import memory_peak_bytes
-from ..flops import Decoder
-from ..program import from_program, model_config, program_params
-from ..weights import leaf_norms
+from ..weights import generate, leaf_norms
 
 
 def run(r: Run, wrap_step=None) -> Outcome:
@@ -35,9 +33,9 @@ def run(r: Run, wrap_step=None) -> Outcome:
     from repro.train.optimizer import OptConfig, init_opt_state
     from repro.train.train_step import TrainConfig, make_train_step
 
-    c, tr = r.config, r.traffic
-    dec = Decoder.from_config(c)
-    model = get_model(model_config(c))
+    c, tr, arch = r.config, r.traffic, r.arch
+    dec = arch.counts(c)
+    model = get_model(arch.model_config(c))
     ocfg = OptConfig(**tr["opt"])
     tcfg = TrainConfig(opt=ocfg, z_loss=float(tr["z_loss"]))
     step_fn = jax.jit(make_train_step(model, tcfg),
@@ -51,11 +49,14 @@ def run(r: Run, wrap_step=None) -> Outcome:
         return {k: jnp.asarray(v)
                 for k, v in gen.packed_rows(tr, r.seed, i, vocab).items()}
 
-    norms = jax.jit(lambda t: leaf_norms(from_program(t)))
-    change = jax.jit(lambda a, z: leaf_norms(from_program(jax.tree.map(
-        lambda x, y: x.astype(jnp.float32) - y.astype(jnp.float32), a, z))))
+    norms = jax.jit(lambda t: leaf_norms(arch.from_program(t),
+                                         arch.UNSTACKED))
+    change = jax.jit(lambda a, z: leaf_norms(arch.from_program(jax.tree.map(
+        lambda x, y: x.astype(jnp.float32) - y.astype(jnp.float32), a, z)),
+        arch.UNSTACKED))
 
-    params = program_params(model, c, r.seed)
+    params = generate(arch.weight_shapes(c), r.seed, c["torch_dtype"],
+                      convert=lambda w: arch.to_program(model, w))
     opt = init_opt_state(params)
     p0 = jax.tree.map(jnp.copy, params)
     jax.block_until_ready((params, opt, p0))

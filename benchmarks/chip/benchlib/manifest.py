@@ -5,13 +5,16 @@ per-layer metric is a file of its own, found by its name:
 
     configs/<config>.json      the configuration as it is run
     configs/<config>.py        its plain reference (imports no program code)
+    archs/<architecture>.py    how the program runs the configuration's
+                               "architecture": its ModelConfig, weights,
+                               parameter tree and operation counts
     traffic/<traffic>.json     parameters of one traffic mix; "kind" names
                                the general driver that reads it
     metrics/<metric>.py        a reader: ``read(ctx) -> float | None``
     limits/<cell>.json         the correctness limits of one cell
 
-So a cell, a configuration, a traffic mix or a metric is added with new
-files and new `BENCHMARK.json` entries only.
+So a cell, a configuration, an architecture, a traffic mix or a metric
+is added with new files and new `BENCHMARK.json` entries only.
 """
 from __future__ import annotations
 
@@ -201,6 +204,16 @@ class Manifest:
         path = (self.root / self.configs[config]["file"]).with_suffix(".py")
         return load_module(path, f"bench_ref_{config}")
 
+    def architecture(self, config: str):
+        """The module `archs/<architecture>.py` of the configuration's
+        "architecture" key; None for a configuration that names none
+        (one that runs no model)."""
+        name = self.config(config).get("architecture")
+        if name is None:
+            return None
+        _check_name(f"config {config} architecture", name)
+        return load_architecture(name, self.bench_dir)
+
     def traffic(self, name: str) -> dict:
         return _load_json(self.bench_dir / "traffic" / f"{name}.json")
 
@@ -217,6 +230,12 @@ def _load_json(path: Path) -> dict:
         return json.loads(path.read_text())
     except (OSError, json.JSONDecodeError) as e:
         raise ManifestError(f"cannot read {path}: {e}") from e
+
+
+def load_architecture(name: str, bench_dir: Path = BENCH_DIR):
+    """archs/<name>.py of the benchmark in bench_dir, loaded."""
+    return load_module(bench_dir / "archs" / f"{name}.py",
+                       f"bench_arch_{name}")
 
 
 def load_module(path: Path, modname: str):

@@ -9,38 +9,12 @@ from __future__ import annotations
 
 from typing import Dict, Tuple
 
-from .flops import Decoder
-
 
 def key_for(seed: int, stream: int):
     import jax
     key = jax.random.key(int(seed) & 0xFFFFFFFF)
     return jax.random.fold_in(jax.random.fold_in(key, int(seed) >> 32),
                               stream)
-
-
-def decoder_shapes(m: Decoder) -> Dict[str, Tuple[tuple, float, float]]:
-    """name -> (shape, std, mean) of a dense decoder's canonical weights,
-    layers stacked on the first axis. Biases and norm scales are random
-    too, so that every path of the block carries signal."""
-    L, d, f = m.layers, m.d_model, m.d_ff
-    q, kv = m.heads * m.head_dim, m.kv_heads * m.head_dim
-    return {
-        "embed": ((m.vocab, d), 0.02, 0.0),
-        "ln1": ((L, d), 0.1, 1.0),
-        "wq": ((L, d, q), d ** -0.5, 0.0),
-        "bq": ((L, q), 0.1, 0.0),
-        "wk": ((L, d, kv), d ** -0.5, 0.0),
-        "bk": ((L, kv), 0.1, 0.0),
-        "wv": ((L, d, kv), d ** -0.5, 0.0),
-        "bv": ((L, kv), 0.1, 0.0),
-        "wo": ((L, q, d), q ** -0.5, 0.0),
-        "ln2": ((L, d), 0.1, 1.0),
-        "w_gate": ((L, d, f), d ** -0.5, 0.0),
-        "w_up": ((L, d, f), d ** -0.5, 0.0),
-        "w_down": ((L, f, d), f ** -0.5, 0.0),
-        "final_norm": ((d,), 0.1, 1.0),
-    }
 
 
 def _gen(shapes, dtype, key):
@@ -54,12 +28,13 @@ def _gen(shapes, dtype, key):
     return out
 
 
-def decoder_weights(m: Decoder, seed: int, dtype: str = "bfloat16",
-                    convert=None):
-    """Canonical weights (see decoder_shapes) in `dtype`; with `convert`,
-    what convert(weights) returns, made in the same jitted call."""
+def generate(shapes: Dict[str, Tuple[tuple, float, float]], seed: int,
+             dtype: str = "bfloat16", convert=None):
+    """Arrays by name from `shapes` (name -> (shape, std, mean)), normal
+    draws in `dtype`, made from the seed in one jitted call: leaf i of
+    the sorted names draws from fold_in(key_for(seed, 0), i). With
+    `convert`, what convert(arrays) returns, made in the same call."""
     import jax
-    shapes = decoder_shapes(m)
 
     def make(key):
         w = _gen(shapes, dtype, key)
@@ -84,14 +59,15 @@ def matrix_blocks(n: int, bs: int, seed: int, stream: int):
     return make(key_for(seed, stream))
 
 
-def leaf_norms(w: dict) -> dict:
-    """L2 norm of each leaf, layer by layer for the stacked ones:
-    {"wq.0": ..., "embed": ...} (traceable)."""
+def leaf_norms(w: dict, unstacked) -> dict:
+    """L2 norm of each leaf, layer by layer for those stacked on the first
+    axis (all but the names in `unstacked`): {"wq.0": ..., "embed": ...}
+    (traceable)."""
     import jax.numpy as jnp
     out = {}
     for k, a in w.items():
         a = a.astype(jnp.float32)
-        if k in ("embed", "final_norm"):
+        if k in unstacked:
             out[k] = jnp.sqrt(jnp.sum(a * a))
         else:
             per = jnp.sqrt(jnp.sum(a * a, axis=tuple(range(1, a.ndim))))

@@ -10,7 +10,8 @@ embedding. No kernels, no cache, no batching: one sequence at a time,
 every matrix product at `Precision.HIGHEST`.
 
 It imports nothing of the program. Its weights are made again from the
-seed by the benchmark's generator, in the type the program serves them
+seed by the benchmark's generator, from the shapes of the benchmark's
+`archs/dense_decoder.py`, in the type the program serves them
 (bfloat16), and widened to float32 here.
 
 The control is the same computation one precision step down from the
@@ -23,14 +24,23 @@ to float8 (one scale per row), gradients straight through the rounding.
 from __future__ import annotations
 
 import functools
+from pathlib import Path
 from typing import List, Sequence, Tuple
 
 import numpy as np
 
 from benchlib.flops import Decoder
-from benchlib.weights import decoder_weights
+from benchlib.manifest import load_architecture
+from benchlib.weights import generate
 
 F8_MAX = 448.0      # largest finite float8_e4m3fn
+# the canonical weights' names, shapes and layout
+DENSE = load_architecture("dense_decoder", Path(__file__).parents[1])
+
+
+def weights(c: dict, seed: int) -> dict:
+    """The canonical weights, from the seed, in the configuration's type."""
+    return generate(DENSE.weight_shapes(c), seed, c["torch_dtype"])
 
 
 def _rms(x, scale, eps):
@@ -197,7 +207,7 @@ def served_gaps(c: dict, seed: int,
     import json
     import jax
     import jax.numpy as jnp
-    w = decoder_weights(Decoder.from_config(c), seed, c["torch_dtype"])
+    w = weights(c, seed)
     ref, ctrl = _programs(json.dumps(c, sort_keys=True))
     q = to_f8(w) if control else None
     gaps, cgaps = [], []
@@ -272,13 +282,12 @@ def reference_steps(c: dict, seed: int, batches, o: dict, z: float,
     act, prec = (jnp.bfloat16, jax.lax.Precision.DEFAULT) if quant \
         else (jnp.float32, hi)
     grad = _row_loss(c, z, act, prec, quant)
-    dec = Decoder.from_config(c)
     widen = jax.jit(lambda t: jax.tree.map(
         lambda a: a.astype(jnp.float32), t))
     add = jax.jit(lambda a, b: jax.tree.map(jnp.add, a, b),
                   donate_argnums=(0,))
-    norms = jax.jit(leaf_norms)
-    p = widen(decoder_weights(dec, seed, c["torch_dtype"]))
+    norms = jax.jit(lambda t: leaf_norms(t, DENSE.UNSTACKED))
+    p = widen(weights(c, seed))
     m = jax.tree.map(jnp.zeros_like, p)
     v = jax.tree.map(jnp.zeros_like, p)
 
@@ -299,7 +308,8 @@ def reference_steps(c: dict, seed: int, batches, o: dict, z: float,
             return (pf - lr * d).astype(c["torch_dtype"])
         # the new parameters leave this call in bfloat16, so the rounding
         # cannot be folded away; `widen` takes them back to float32
-        return jax.tree.map(new, p, m, v), m, v, leaf_norms(g)
+        return (jax.tree.map(new, p, m, v), m, v,
+                leaf_norms(g, DENSE.UNSTACKED))
 
     losses, g1 = [], None
     for step, batch in enumerate(batches, start=1):
@@ -321,7 +331,7 @@ def reference_steps(c: dict, seed: int, batches, o: dict, z: float,
             g1 = {k: float(x) for k, x in gn.items()}
         losses.append(lsum / len(idx))
     del m, v
-    p0 = widen(decoder_weights(dec, seed, c["torch_dtype"]))
+    p0 = widen(weights(c, seed))
     moved = norms(jax.tree.map(jnp.subtract, p, p0))
     return losses, g1, {k: float(x) for k, x in moved.items()}
 

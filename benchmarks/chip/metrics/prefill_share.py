@@ -1,9 +1,15 @@
-"""Share of the window's slot-steps that teacher-forced a prompt token,
-counted from the requests sent and the engine steps driven."""
+"""The prompt chunks' share of the serving programs' device time in the
+traced window: `jit_prefill_chunk` over itself plus `jit_serve_step`.
+Where prompts are teacher-forced through the decode step (a model with
+no chunk program), they run inside `jit_serve_step`, where the trace
+cannot tell them from decoding: then it reads nothing (the driver's
+notes give the window's teacher-forced tokens)."""
+from benchlib.readers import program_time
 
 
 def read(ctx):
-    total = ctx.get("slot_steps")
-    if not total:
+    n, step_s = program_time(ctx, "jit_serve_step")
+    if not n or not ctx.get("prefill_chunked"):
         return None
-    return 100.0 * ctx["prefill_slot_steps"] / total
+    _, chunk_s = program_time(ctx, "jit_prefill_chunk")
+    return 100.0 * chunk_s / (chunk_s + step_s)

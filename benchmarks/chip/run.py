@@ -8,8 +8,10 @@ Runs from the root of a checkout, on a machine whose JAX sees the chips
 the cell asks for; it exits non-zero, printing no result, without them.
 The cell names a configuration and a traffic mix; the traffic file's
 "kind" names the general driver (`benchlib/drivers/<kind>.py`) that runs
-it. Set-up (weights made on the device from the seed, every shape warmed,
-programs compiled or loaded from the compile cache at `<checkout>/.jax_cache`)
+it, and a model configuration's "architecture" the file
+(`archs/<architecture>.py`) that maps it onto the program. Set-up
+(weights made on the device from the seed, every shape warmed, programs
+compiled or loaded from the compile cache at `<checkout>/.jax_cache`)
 is timed as `setup_s`; then the window measures for `--seconds`. Once it
 closes, the program's output is compared with the configuration's plain
 reference (`configs/<config>.py`).
@@ -69,6 +71,7 @@ def prepare(args, root: Path = ROOT, bench: Path = BENCH,
     config = man.config(cell.config)
     limits = man.limits(cell.name)
     reference = man.reference(cell.config)
+    arch = man.architecture(cell.config)
     if devices is None:
         devices = dev.require_chips(cell.chips)
         peaks = dev.peaks_for(devices[0].device_kind)
@@ -76,8 +79,8 @@ def prepare(args, root: Path = ROOT, bench: Path = BENCH,
         peaks = dev.peaks_for("TPU v5 lite")
     run = Run(workload=cell.name, seed=args.seed, seconds=args.seconds,
               trace=bool(args.trace), config=config, traffic=traffic,
-              limits=limits, reference=reference, devices=devices,
-              peaks=peaks, root=root, t_start=T_START,
+              limits=limits, reference=reference, arch=arch,
+              devices=devices, peaks=peaks, root=root, t_start=T_START,
               control=bool(args.control))
     return man, cell, run
 

@@ -32,11 +32,13 @@ SERVE_MODEL = dict(TINY_MODEL, hidden_size=896, intermediate_size=4864,
                    num_attention_heads=14)
 
 
-def tiny_run(cell, control=False):
+def tiny_run(cell, control=False, root=bench.ROOT, bench_dir=bench.BENCH):
+    """The driver of the cell, and its Run cut to the tiny sizes above;
+    `root` and `bench_dir` name another checkout's benchmark."""
     args = bench.parse(["--workload", cell, "--seed", str(SEED),
                         "--seconds", "1", "--trace", "0",
                         "--control", str(int(control))])
-    man, c, r = bench.prepare(args, devices=jax.devices())
+    man, c, r = bench.prepare(args, root, bench_dir, devices=jax.devices())
     kind = r.traffic["kind"]
     if kind == "serve":
         r.config.update(SERVE_MODEL)

@@ -106,3 +106,52 @@ def test_recorded_trace_of_the_4096_block_graph():
     bd = red.breakdown()
     assert 0 < len(bd["device_ops"]) <= 10 and len(bd["idle_gaps"]) <= 10
     assert bd["device_ops"][0][0] == "convolution_add_fusion"
+
+
+def serve_trace():
+    """Two decode steps of 30 ms, one carrying a 4 ms prompt chunk."""
+    return tracered.reduce(events(
+        ops=[("a", 0.0, 0.064)], spans=[("bench:window", 0.0, 0.1)],
+        modules=[("jit_prefill_chunk(7)", 0.0, 0.004),
+                 ("jit_serve_step(3)", 0.004, 0.034),
+                 ("jit_serve_step(3)", 0.034, 0.064)]))
+
+
+def test_prefill_share_is_the_chunks_device_time():
+    from benchlib.manifest import Manifest
+    from benchpath import ROOT
+    read = Manifest.load(ROOT, BENCH).reader("prefill_share")
+    red = serve_trace()
+    assert read({"reduced": red, "prefill_chunked": True}) == \
+        pytest.approx(100 * 4 / 64)
+    # prompts teacher-forced through the decode step: the trace cannot
+    # part them from decoding
+    assert read({"reduced": red, "prefill_chunked": False}) is None
+    assert read({"reduced": None, "prefill_chunked": True}) is None
+
+
+def test_serve_step_mfu_counts_the_tokens_of_the_traced_steps():
+    from benchlib.manifest import Manifest
+    from benchpath import ROOT
+    read = Manifest.load(ROOT, BENCH).reader("serve_step_mfu")
+    p = peaks_for("TPU v5 lite")
+    ctx = {"reduced": serve_trace(), "peaks": p, "serve_steps_traced": 2,
+           "serve_flops_traced": 2 * 197e12 * 0.03 / 100}
+    assert read(ctx) == pytest.approx(1.0)
+    assert read(dict(ctx, serve_flops_traced=0.0)) is None
+
+
+def test_decode_contexts_follow_each_prompt_path():
+    from types import SimpleNamespace
+    from benchlib.drivers.serve import Tracked, decode_contexts
+    req = SimpleNamespace(prompt=[5] * 600, admitted_step=10)
+    x = Tracked(due=0.0, req=req, token_steps=[12, 13, 14, 15])
+    # chunked: the first token came from the last chunk (step 12)
+    assert decode_contexts([x], True, 11, 15) == [601, 602, 603]
+    assert decode_contexts([x], True, 13, 14) == [602]
+    # teacher-forced: prompt token j at step 11 + j, context j + 1; the
+    # last one (step 610) also made output token 0 at context 600
+    req = SimpleNamespace(prompt=[5] * 4, admitted_step=10)
+    x = Tracked(due=0.0, req=req, token_steps=[14, 15])
+    assert sorted(decode_contexts([x], False, 10, 15)) == [1, 2, 3, 4, 5]
+    assert decode_contexts([x], False, 12, 14) == [4, 3]
